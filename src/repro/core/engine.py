@@ -50,8 +50,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import bloom as bloom_lib
 from repro.core import diffstore as ds
@@ -66,6 +65,7 @@ from repro.core.graph import (
 )
 from repro.core.semiring import Semiring, reduce_pair
 from repro.kernels.ell_spmv import ell_spmv
+from repro.kernels.interpret import resolve_interpret
 from repro.obs import trace as obs_trace
 
 # module (not name) import: kernels/fused_sweep.py imports repro.core for the
@@ -265,12 +265,6 @@ def _ell_weights(cfg: EngineConfig, g: GraphArrays) -> Array:
     return g.ell_w
 
 
-def _interpret(cfg: EngineConfig) -> bool:
-    if cfg.interpret is not None:
-        return cfg.interpret
-    return jax.default_backend() != "tpu"
-
-
 def ell_step(
     cfg: EngineConfig, cur: Array, g: GraphArrays, *, carry: Array | None = None
 ) -> Array:
@@ -293,7 +287,7 @@ def ell_step(
         kcarry,
         semiring=sr.kernel_name,
         block_v=cfg.ell_block_v,
-        interpret=_interpret(cfg),
+        interpret=resolve_interpret(cfg.interpret),
         hop_cap=sr.hop_cap,
     )
 
@@ -542,7 +536,7 @@ def _sweep_body(
             hop_cap=sr.hop_cap,
             block_v=cfg.ell_block_v,
             drop_mode=cfg.drop.mode if cfg.drop.enabled() else "none",
-            interpret=_interpret(cfg),
+            interpret=resolve_interpret(cfg.interpret),
             **kw,
         )
         dstore = ds.DiffStore(out.d_iters, out.d_vals, out.d_count)
@@ -906,12 +900,12 @@ def maintain_sharded(
     ``data`` axis.  ``g`` must be in the :class:`ShardIndex` edge layout
     (cells grouped by destination shard) and V divisible by the axis size."""
     sspec = _state_pspecs(state)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_maintain_core, cfg, axis=DATA_AXIS),
         mesh=mesh,
         in_specs=(sspec, _graph_pspecs(g), P(None, DATA_AXIS)),
         out_specs=(sspec, _stats_pspecs()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(state, g, _dirty_2d(cfg, dirty))
 
@@ -986,12 +980,12 @@ def batched_step_sharded(
     """Sharded twin of :func:`batched_step`: one dispatch scatters a δE chunk
     to the owning shards and runs the vertex-sharded maintenance sweep."""
     sspec, gspec = _state_pspecs(state), _graph_pspecs(g)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_batched_core_sharded, cfg, axis=DATA_AXIS),
         mesh=mesh,
         in_specs=(sspec, gspec, UpdateBatch(*([P()] * len(UpdateBatch._fields)))),
         out_specs=(sspec, gspec, _stats_pspecs()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(state, g, upd)
 
@@ -1286,6 +1280,8 @@ class DiffIFE:
             drop_rows=drop_rows,
             join_rows=join_rows,
         )
+        if self.num_shards > 1:
+            self.state = self._on_mesh(self.state, _state_pspecs(self.state))
         # descending so pop() hands out the lowest free slot first
         self._free_slots: list[int] = sorted(
             (
@@ -1356,7 +1352,7 @@ class DiffIFE:
             self._ell_width = width
             self._ell_index = EllIndex(snap, width)
             nbr, ell_w = jnp.asarray(nbr_np), jnp.asarray(w_np)
-        return GraphArrays(
+        g = GraphArrays(
             src=jnp.asarray(src),
             dst=jnp.asarray(dst),
             weight=jnp.asarray(w),
@@ -1365,6 +1361,19 @@ class DiffIFE:
             in_degree=jnp.asarray(snap.in_degree, jnp.int32),
             nbr=nbr,
             ell_w=ell_w,
+        )
+        return self._on_mesh(g, _graph_pspecs(g))
+
+    def _on_mesh(self, tree, specs):
+        """Lay ``tree`` out on the mesh as the sharded programs return it, so
+        their first call compiles the program every later call reuses."""
+        return jax.device_put(
+            tree,
+            jax.tree.map(
+                lambda spec: NamedSharding(self.mesh, spec),
+                specs,
+                is_leaf=lambda x: isinstance(x, P),
+            ),
         )
 
     def _shard_sync(self, ops, snap: GraphSnapshot | None = None) -> list | None:

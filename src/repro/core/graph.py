@@ -88,6 +88,13 @@ class GraphSnapshot:
         return nbr, w, d
 
 
+def edge_capacity(initial: Sequence[tuple], log: Sequence[tuple]) -> int:
+    """Edge slots a run needs: the initial edges plus every insert of the
+    ``(u, v, label, w, ±1)`` update log.  A delete frees its slot for reuse,
+    so the live count never passes this."""
+    return len(initial) + sum(1 for u in log if u[4] > 0)
+
+
 class DynamicGraph:
     """Host-side dynamic graph with slot-recycling edge storage."""
 
@@ -116,16 +123,30 @@ class DynamicGraph:
         self._slot: dict[tuple[int, int, int], int] = {}
         self._free: list[int] = list(range(cap - 1, n - 1, -1))
         self.version = 0  # G_k
-        for i, e in enumerate(edges):
-            u, v = int(e[0]), int(e[1])
-            w = float(e[2]) if (weighted and len(e) > 2) else 1.0
-            lbl = int(e[3]) if len(e) > 3 else NO_LABEL
-            self.src[i], self.dst[i] = u, v
-            self.weight[i], self.label[i] = w, lbl
-            self.valid[i] = True
-            self.out_degree[u] += 1
-            self.in_degree[v] += 1
-            self._slot[(u, v, lbl)] = i
+        if n == 0:
+            return
+        src = np.fromiter((e[0] for e in edges), np.int64, n)
+        dst = np.fromiter((e[1] for e in edges), np.int64, n)
+        if min(src.min(), dst.min()) < 0 or max(src.max(), dst.max()) >= num_vertices:
+            raise ValueError(f"edge endpoint outside [0, {num_vertices})")
+        self.src[:n], self.dst[:n] = src, dst
+        if weighted:
+            self.weight[:n] = np.fromiter(
+                (e[2] if len(e) > 2 else 1.0 for e in edges), np.float64, n
+            )
+        else:
+            self.weight[:n] = 1.0
+        self.label[:n] = np.fromiter(
+            (e[3] if len(e) > 3 else NO_LABEL for e in edges), np.int64, n
+        )
+        self.valid[:n] = True
+        self.out_degree[:] = np.bincount(src, minlength=self.num_vertices)
+        self.in_degree[:] = np.bincount(dst, minlength=self.num_vertices)
+        # a repeated (u, v, label) keeps its last slot, as slot-by-slot
+        # insertion would
+        self._slot = dict(
+            zip(zip(src.tolist(), dst.tolist(), self.label[:n].tolist()), range(n))
+        )
 
     # ------------------------------------------------------------ durability
     def state_dict(self) -> tuple[dict[str, np.ndarray], dict]:
@@ -360,21 +381,28 @@ class ShardIndex:
         counts = np.bincount(
             snap.dst[live] // self.vertices_per_shard, minlength=n
         )
-        cap = max(
-            int(counts.max(initial=0)),
-            -(-snap.capacity // n),  # even spread of the host capacity
-            int(min_capacity),
-            8,
-        )
+        # Inserts land where live edges do (the in-degree distribution), so
+        # each shard gets room for the fullest shard's share of the free host
+        # slots.  All shards together then hold (n · fullest / live) times
+        # the host capacity: the layout's skew, not its shard count.
+        full, free = int(counts.max(initial=0)), snap.capacity - live.size
+        room = -(-free * full // live.size) if live.size else -(-free // n)
+        cap = max(full + room, int(min_capacity), 8)
         self.shard_capacity = -(-cap // 8) * 8
-        self.cell_of: dict[int, int] = {}  # edge slot → linear cell index
+        # cells fill in ascending slot order within each shard, like
+        # EllIndex / to_ell
+        shard = snap.dst[live].astype(np.int64) // self.vertices_per_shard
+        rank = np.empty(live.size, dtype=np.int64)
+        rank[np.argsort(shard, kind="stable")] = np.arange(live.size) - np.repeat(
+            np.cumsum(counts) - counts, counts
+        )
+        # edge slot → linear cell index
+        self.cell_of: dict[int, int] = dict(
+            zip(live.tolist(), (shard * self.shard_capacity + rank).tolist())
+        )
         self.dead: dict[int, tuple[int, int]] = {}  # freed cell → endpoints
-        self.fill = np.zeros(n, dtype=np.int64)
+        self.fill = counts.astype(np.int64)
         self.free: dict[int, list[int]] = {}
-        for e in live:  # ascending slot order, like EllIndex / to_ell
-            sh = int(snap.dst[e]) // self.vertices_per_shard
-            self.cell_of[int(e)] = sh * self.shard_capacity + int(self.fill[sh])
-            self.fill[sh] += 1
 
     def _alloc(self, shard: int) -> int:
         cells = self.free.get(shard)
@@ -421,11 +449,12 @@ class ShardIndex:
         dst = np.zeros(size, dtype=np.int32)
         w = np.zeros(size, dtype=np.float32)
         valid = np.zeros(size, dtype=bool)
-        for slot, lin in self.cell_of.items():
-            src[lin] = snap.src[slot]
-            dst[lin] = snap.dst[slot]
-            w[lin] = snap.weight[slot]
-            valid[lin] = snap.valid[slot]
+        slot = np.fromiter(self.cell_of.keys(), np.int64, len(self.cell_of))
+        lin = np.fromiter(self.cell_of.values(), np.int64, len(self.cell_of))
+        src[lin] = snap.src[slot]
+        dst[lin] = snap.dst[slot]
+        w[lin] = snap.weight[slot]
+        valid[lin] = snap.valid[slot]
         # freed cells keep their last endpoints, matching the scatter path
         # (writes_for) and the unsharded snapshot: the VDC identity-overwrite
         # rule still needs a deleted edge's old destination to look dirty.

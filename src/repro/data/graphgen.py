@@ -29,11 +29,20 @@ def powerlaw_graph(
     probs = ranks ** (-exponent)
     probs /= probs.sum()
     perm = rng.permutation(num_vertices)
+    # rng.choice(V, p=probs) draws cdf.searchsorted(rng.random(), side="right")
+    # but re-checks and re-sums all V probabilities on every call; building
+    # the CDF once gives the same edges without that O(V) per draw
+    cdf = probs.cumsum()
+    cdf /= cdf[-1]
+
+    def endpoint() -> int:
+        return int(perm[cdf.searchsorted(rng.random(), side="right")])
+
     seen: set[tuple[int, int]] = set()
     edges: list[Edge] = []
     while len(edges) < num_edges:
-        u = int(perm[rng.choice(num_vertices, p=probs)])
-        v = int(perm[rng.choice(num_vertices, p=probs)])
+        u = endpoint()
+        v = endpoint()
         if u == v or (u, v) in seen:
             continue
         seen.add((u, v))
@@ -63,7 +72,7 @@ def uniform_graph(
 def split_90_10(edges: list[Edge], *, seed: int = 0) -> tuple[list[Edge], list[Edge]]:
     """Paper §6.1: shuffle, 90% initial graph, 10% update stream."""
     rng = np.random.default_rng(seed)
-    order = rng.permutation(len(edges))
+    order = rng.permutation(len(edges)).tolist()
     cut = int(len(edges) * 0.9)
     return [edges[i] for i in order[:cut]], [edges[i] for i in order[cut:]]
 
@@ -83,13 +92,27 @@ def update_stream(
     rng = np.random.default_rng(seed)
     present = {(int(e[0]), int(e[1])): e for e in existing}
     pool = list(insert_pool or [])
+    # A delete takes the k-th present edge in insertion order.  `order` logs
+    # every key at the position it entered `present`, and `alive` counts the
+    # positions still present, so the k-th is found in O(log n).
+    order = list(present)
+    alive = _Fenwick(len(order) + num_batches * batch_size, len(order))
+    where = dict(zip(order, range(len(order))))
+
+    def add(key, e):
+        present[key] = e
+        where[key] = len(order)
+        alive.add(len(order), 1)
+        order.append(key)
+
     batches = []
     for _ in range(num_batches):
         batch = []
         for _ in range(batch_size):
             if present and rng.random() < delete_fraction:
-                key = list(present)[int(rng.integers(len(present)))]
+                key = order[alive.find(int(rng.integers(len(present))))]
                 e = present.pop(key)
+                alive.add(where.pop(key), -1)
                 lbl = int(e[3]) if len(e) > 3 else 0
                 batch.append((key[0], key[1], lbl, float(e[2]), -1))
             else:
@@ -99,18 +122,46 @@ def update_stream(
                     if key in present:
                         continue
                     lbl = int(e[3]) if len(e) > 3 else 0
-                    present[key] = e
+                    add(key, e)
                     batch.append((key[0], key[1], lbl, float(e[2]), +1))
                 else:
                     u, v = (int(x) for x in rng.integers(0, num_vertices, 2))
                     if u == v or (u, v) in present:
                         continue
                     w = float(rng.integers(1, 11))
-                    present[(u, v)] = (u, v, w)
+                    add((u, v), (u, v, w))
                     batch.append((u, v, 0, w, +1))
         if batch:
             batches.append(batch)
     return batches
+
+
+class _Fenwick:
+    """Counts over positions ``0..size-1`` (the first ``ones`` start at 1)
+    with point updates and k-th-one search, both O(log size)."""
+
+    def __init__(self, size: int, ones: int) -> None:
+        i = np.arange(1, size + 1)
+        # node i covers positions (i - lowbit(i), i]; count the ones among them
+        self._tree = np.clip(np.minimum(i, ones) - (i - (i & -i)), 0, None).tolist()
+        self._top = 1 << max(size.bit_length() - 1, 0)
+
+    def add(self, pos: int, delta: int) -> None:
+        i = pos + 1
+        while i <= len(self._tree):
+            self._tree[i - 1] += delta
+            i += i & -i
+
+    def find(self, k: int) -> int:
+        """Position of the ``k``-th (0-based) counted position."""
+        pos, step = 0, self._top
+        while step:
+            nxt = pos + step
+            if nxt <= len(self._tree) and self._tree[nxt - 1] <= k:
+                pos = nxt
+                k -= self._tree[nxt - 1]
+            step >>= 1
+        return pos
 
 
 def ldbc_like_graph(

@@ -2,10 +2,9 @@
 
 Every kernel signature defaults ``interpret=None``; :func:`resolve_interpret`
 maps ``None`` to the backend default — interpret off-TPU (the kernel body
-executes in Python for validation), compiled Mosaic on TPU.  A caller that
-*forces* interpret mode on a TPU backend is almost certainly measuring the
-Python emulation instead of the kernel, so the first such resolution logs a
-one-time warning.
+executes in Python for validation), compiled Mosaic on TPU.  Forcing
+interpret mode on a TPU backend is refused: the emulated kernel would stand
+in for the compiled one and hide its real cost, or its failure to lower.
 
 This module is a leaf (no intra-package imports) so the kernels can use it
 without creating an import cycle with :mod:`repro.kernels.ops`.
@@ -13,12 +12,7 @@ without creating an import cycle with :mod:`repro.kernels.ops`.
 
 from __future__ import annotations
 
-import logging
-
 import jax
-
-_log = logging.getLogger("repro.kernels")
-_warned_tpu_interpret = False
 
 
 def default_interpret() -> bool:
@@ -29,20 +23,14 @@ def default_interpret() -> bool:
 def resolve_interpret(interpret: bool | None) -> bool:
     """Resolve a kernel's ``interpret`` argument (``None`` → backend default).
 
-    Logs once when interpret mode ends up running on a TPU backend — the
-    emulated kernel is orders of magnitude slower than the Mosaic lowering
-    and silently hides the kernel's real cost.
+    Raises ``ValueError`` when interpret mode is forced on a TPU backend.
     """
     if interpret is None:
-        interpret = default_interpret()
+        return default_interpret()
     if interpret and jax.default_backend() == "tpu":
-        global _warned_tpu_interpret
-        if not _warned_tpu_interpret:
-            _warned_tpu_interpret = True
-            _log.warning(
-                "Pallas kernel running in interpret mode on a TPU backend: "
-                "this executes the kernel body in Python instead of the "
-                "compiled Mosaic kernel. Pass interpret=False (or leave it "
-                "None) to use the hardware path."
-            )
+        raise ValueError(
+            "interpret=True on a TPU backend: the Pallas kernel would run as a "
+            "Python emulation instead of compiled Mosaic; pass interpret=None "
+            "or False"
+        )
     return bool(interpret)

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Every kernel signature defaults ``interpret=None`` → :func:`default_interpret`
-(interpret off-TPU, compiled Mosaic on TPU; see ``kernels/interpret.py`` for
-the one-time warning when interpret mode is forced on a TPU backend).
-Callers can still force either mode explicitly.
+(interpret off-TPU, compiled Mosaic on TPU).  Callers may force compiled
+Mosaic anywhere; forcing interpret mode on a TPU raises
+(``kernels/interpret.py``).
 """
 
 from __future__ import annotations

@@ -169,11 +169,11 @@ def build_log(args):
 
 
 def build_session(args):
-    from repro.core.graph import DynamicGraph
+    from repro.core.graph import DynamicGraph, edge_capacity
     from repro.core.session import CQPSession
 
-    edges, initial, log = build_log(args)
-    graph = DynamicGraph(args.v, initial, capacity=len(edges) * 4 + 64)
+    _, initial, log = build_log(args)
+    graph = DynamicGraph(args.v, initial, capacity=edge_capacity(initial, log))
     mesh = make_mesh(args.mesh, args.shards)
     if mesh is not None and args.engine != "dense":
         raise SystemExit("--mesh shards the dense engine only")
@@ -381,7 +381,13 @@ def serve(args) -> dict:
     phases.extend("deregister", [x / 1e3 for x in dereg_ms])
     if sup is not None:
         phases.extend("checkpoint", sup.checkpoint_s)
+    import jax
+
+    dev = jax.devices()[0]
     out = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
         "engine": args.engine,
         "queries": args.queries,
         "final_queries": session.num_queries,
@@ -547,7 +553,7 @@ def main() -> None:
     ap.add_argument(
         "--backend",
         choices=("coo", "ell", "fused"),
-        default="ell",
+        default="coo",
         help="sweep aggregator: coo=segment-reduce, ell=Pallas SpMV, "
         "fused=maintenance megakernel (one pallas_call per iteration)",
     )
@@ -696,6 +702,9 @@ def main() -> None:
         args.queries = min(args.queries, 4)
         args.updates, args.batch = min(args.updates, 32), min(args.batch, 8)
         args.max_iters = min(args.max_iters, 24)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     serve(args)
 
 

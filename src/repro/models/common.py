@@ -185,7 +185,6 @@ def dlse_decode_attention(
     """
     mesh = _ACTIVATION_MESH[0]
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, hq, _, d = q.shape
     hkv = ck.shape[1]
@@ -211,7 +210,7 @@ def dlse_decode_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out.reshape(q_l.shape[0], hq, 1, d).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -221,7 +220,7 @@ def dlse_decode_attention(
             P(),
         ),
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, kv_valid_len)
 
 
@@ -241,7 +240,6 @@ def dlse_mla_decode_attention(
     crosses the ICI either, on top of the KV gather it already saves."""
     mesh = _ACTIVATION_MESH[0]
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, h, _, qk = q.shape
     rd = qk - nope_dim
@@ -269,7 +267,7 @@ def dlse_mla_decode_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out[:, :, None, :].astype(q_l.dtype)  # [B, H, 1, vd]
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -281,7 +279,7 @@ def dlse_mla_decode_attention(
             P(),
         ),
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, ckv, krope, wuk, wuv, kv_valid_len)
 
 

@@ -217,7 +217,7 @@ def main(argv=None) -> int:
         SLOConfig,
         build_serving_session,
     )
-    from repro.core.graph import DynamicGraph
+    from repro.core.graph import DynamicGraph, edge_capacity
 
     ap = argparse.ArgumentParser(
         description="Open-loop multi-tenant CQP load generator"
@@ -260,7 +260,13 @@ def main(argv=None) -> int:
 
     ladder = GovernorConfig(representation="prob")
     session = build_serving_session(
-        DynamicGraph(args.v, initial, capacity=len(edges) * 4 + 64),
+        DynamicGraph(
+            args.v,
+            initial,
+            capacity=edge_capacity(
+                initial, [u for log in updates_by_tenant.values() for u in log]
+            ),
+        ),
         ladder=ladder,
         engine=args.engine,
         batch_capacity=args.batch,

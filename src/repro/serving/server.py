@@ -41,6 +41,7 @@ import argparse
 import asyncio
 import dataclasses
 import json
+import logging
 import time
 from collections import deque
 from typing import Callable
@@ -49,7 +50,7 @@ import numpy as np
 
 from repro.core import dropping as dr
 from repro.core import plan as qp
-from repro.core.graph import DynamicGraph
+from repro.core.graph import DynamicGraph, edge_capacity
 from repro.core.governor import GovernorConfig
 from repro.core.session import CQPSession
 from repro.obs import metrics as obs_metrics
@@ -66,6 +67,8 @@ from repro.serving.admission import (
 )
 from repro.serving.metrics import PhaseRecorder, summarize_latency_s
 from repro.serving.tenants import QueryTicket, TenantRegistry, TenantSpec
+
+_log = logging.getLogger("repro.serving")
 
 
 # --------------------------------------------------------------------- config
@@ -601,24 +604,25 @@ class CQPServer:
     def _obs_scrape(self) -> None:
         """Periodic observability tick: publish the session into the obs
         registry, then rewrite the configured file sinks (per-epoch trace
-        flush + metrics snapshot).  Sink errors never take down serving."""
+        flush + metrics snapshot).  A sink that cannot be written is logged
+        and serving goes on; any other error propagates."""
+        self.session.publish_metrics()
+        reg = obs_metrics.get_registry()
+        reg.gauge("serving_epoch", "applied epoch counter").set(self._epoch)
+        reg.gauge("serving_queue_depth", "admitted updates not yet applied").set(
+            len(self._queue)
+        )
+        reg.gauge(
+            "serving_covered_updates", "applied prefix of the admitted stream"
+        ).set(self._covered)
         try:
-            self.session.publish_metrics()
-            reg = obs_metrics.get_registry()
-            reg.gauge("serving_epoch", "applied epoch counter").set(self._epoch)
-            reg.gauge("serving_queue_depth", "admitted updates not yet applied").set(
-                len(self._queue)
-            )
-            reg.gauge(
-                "serving_covered_updates", "applied prefix of the admitted stream"
-            ).set(self._covered)
             if self.config.metrics_out:
                 with open(self.config.metrics_out, "w") as f:
                     json.dump(reg.snapshot(), f, indent=1)
             if self.config.trace_out:
                 obs_trace.get_tracer().export(self.config.trace_out)
-        except Exception:  # pragma: no cover - diagnostics must not kill serving
-            pass
+        except OSError:
+            _log.exception("obs sink write failed at epoch %d", self._epoch)
 
     def _headroom_frac(self) -> float | None:
         governor = getattr(self.session, "_governor", None)
@@ -872,7 +876,7 @@ def _scripted_scenario(args: argparse.Namespace) -> dict:
     ladder = GovernorConfig(representation="prob")
 
     def fresh_graph() -> DynamicGraph:
-        return DynamicGraph(args.v, initial, capacity=len(edges) * 4 + 64)
+        return DynamicGraph(args.v, initial, capacity=edge_capacity(initial, log))
 
     def factory() -> CQPSession:
         return build_serving_session(
@@ -995,6 +999,9 @@ def main(argv=None) -> int:
         args.e = min(args.e, 256)
         args.updates = min(args.updates, 96)
         args.max_iters = min(args.max_iters, 16)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     stats = _scripted_scenario(args)
     summary = {
         "ok": stats["ok"],

@@ -113,3 +113,30 @@ def test_engine_step_equals_kernel_spmv():
     got = ops.spmv(states, jnp.asarray(nbr), jnp.asarray(w), cur, semiring="min_plus", interpret=True)
     want = ife_step(eng.cfg, cur, GraphArrays.from_snapshot(snap))
     np.testing.assert_allclose(got, want)
+
+
+def test_forced_interpret_mode_on_tpu_raises(monkeypatch):
+    """On a TPU backend the default resolves to compiled Mosaic, and forcing
+    interpret mode is refused, through the engine's ELL step too."""
+    from repro.core import engine, plan
+    from repro.kernels import interpret
+
+    monkeypatch.setattr(interpret.jax, "default_backend", lambda: "tpu")
+    assert interpret.resolve_interpret(None) is False
+    assert interpret.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        interpret.resolve_interpret(True)
+    p = plan.sssp(0, max_iters=4)
+    cfg = engine.EngineConfig(
+        num_queries=1, num_vertices=8, max_iters=4, semiring=p.semiring,
+        backend="ell", interpret=True,
+    )
+    g = engine.GraphArrays(
+        *(jnp.zeros(4, dt) for dt in (jnp.int32, jnp.int32, jnp.float32, bool)),
+        out_degree=jnp.zeros(8, jnp.int32),
+        in_degree=jnp.zeros(8, jnp.int32),
+        nbr=jnp.full((8, 8), 8, jnp.int32),
+        ell_w=jnp.zeros((8, 8), jnp.float32),
+    )
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        engine.ell_step(cfg, jnp.zeros((1, 8), jnp.float32), g)

@@ -220,3 +220,30 @@ def test_admission_rejects_do_not_leak_query_slots():
 
     stats = asyncio.run(run())
     assert stats["tenants"]["t"]["queries"] == 0
+
+
+# ----------------------------------------------------------------- obs sinks
+def test_obs_scrape_logs_an_unwritable_sink_and_raises_anything_else(
+    tmp_path, caplog, monkeypatch
+):
+    """A file sink that cannot be written is logged and serving goes on; an
+    error in publishing the session's metrics is a program fault and
+    propagates instead of vanishing."""
+    initial, _ = _workload(num_batches=1)
+    server = CQPServer(
+        _session(initial),
+        config=ServerConfig(
+            chunk_updates=BATCH,
+            metrics_out=str(tmp_path / "missing-dir" / "metrics.json"),
+        ),
+    )
+    with caplog.at_level("ERROR", logger="repro.serving"):
+        server._obs_scrape()
+    assert "obs sink write failed" in caplog.text
+
+    def broken(*_a, **_kw):
+        raise RuntimeError("probe bug")
+
+    monkeypatch.setattr(server.session, "publish_metrics", broken)
+    with pytest.raises(RuntimeError, match="probe bug"):
+        server._obs_scrape()

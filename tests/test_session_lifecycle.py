@@ -326,7 +326,24 @@ def test_cqp_serve_churn_all_engines_subprocess():
         assert out.returncode == 0, out.stdout + out.stderr
         payload = json.loads(out.stdout.strip().splitlines()[-1])
         assert payload["engine"] == engine
+        assert payload["platform"] == "cpu" and payload["device_count"] >= 1
+        assert payload["device_kind"]
         assert payload["registers"] == 1 and payload["deregisters"] == 1
         assert payload["updates_served"] > 0
         if engine != "scratch":
             assert payload["bytes_freed"] > 0
+
+
+def test_cqp_serve_edge_capacity_is_initial_edges_plus_stream_inserts():
+    """The host graph is sized to what the log can occupy: a delete frees a
+    slot for a later insert, so initial + inserts is never exceeded."""
+    from repro.core.graph import DynamicGraph, edge_capacity
+
+    initial = [(0, 1, 1.0), (1, 2, 1.0), (2, 3, 1.0)]
+    log = [(3, 0, 0, 1.0, +1), (0, 1, 0, 1.0, -1), (0, 2, 0, 1.0, +1),
+           (3, 1, 0, 1.0, +1)]
+    cap = edge_capacity(initial, log)
+    assert cap == 6
+    g = DynamicGraph(4, initial, capacity=cap)
+    g.apply_batch(log)  # fits: no "edge capacity exhausted"
+    assert g.num_edges == 5

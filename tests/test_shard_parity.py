@@ -292,9 +292,11 @@ def test_sharded_batched_equals_unsharded_sequential_stream(backend):
         mesh=make_data_mesh(8),
         **kw,
     )
+    cells = bat._shard_index.shard_capacity
     for u in log:
         seq.apply_updates([u])
     bat.apply_updates_batched(log, batch_size=4)
+    assert bat._shard_index.shard_capacity > cells  # the regrow happened
     np.testing.assert_array_equal(seq.answers(), bat.answers())
 
 
