@@ -1,0 +1,9 @@
+"""device_idle: share of the traced window in which no operation ran on the
+device, in percent."""
+
+
+def read(rec):
+    trace = rec["trace"]
+    if not trace or not trace["devices"]:
+        return None
+    return (1.0 - trace["busy_s"] / trace["window_s"]) * 100.0
