@@ -196,12 +196,15 @@ class MaintainStats(NamedTuple):
     det_overflow: Array  # int32 — dropped VT records lost to Det-Drop store
     # evictions THIS sweep: each one is a (v, i) the engine can no longer
     # repair on access, so a nonzero value flags answers at risk of staleness
+    active_edges: Array  # f32 — Σ_i Σ_q live in-edges of the sched|repair
+    # rows (the edges a work-efficient sweep would read; float so 64
+    # iterations of Q × 2^23 edges cannot overflow)
     sched_sizes: Array  # int32 [ITER_TRACE] — |sched_i| per iteration
     frontier_sizes: Array  # int32 [ITER_TRACE] — |frontier_{i+1}| per iteration
 
     SCALAR_FIELDS = (
         "iters_run", "scheduled", "changed", "repairs", "written",
-        "removed", "dropped", "jwritten", "det_overflow",
+        "removed", "dropped", "jwritten", "det_overflow", "active_edges",
     )
     VECTOR_FIELDS = ("sched_sizes", "frontier_sizes")
 
@@ -209,7 +212,7 @@ class MaintainStats(NamedTuple):
 def zeros_stats() -> MaintainStats:
     z = jnp.zeros((), jnp.int32)
     t = jnp.zeros((ITER_TRACE,), jnp.int32)
-    return MaintainStats(z, z, z, z, z, z, z, z, z, t, t)
+    return MaintainStats(z, z, z, z, z, z, z, z, z, jnp.zeros((), jnp.float32), t, t)
 
 
 # --------------------------------------------------------------------------- IFE primitives
@@ -439,7 +442,8 @@ def _sweep_body(
         off = jax.lax.axis_index(axis).astype(jnp.int32) * num_local
         # the one O(V) exchange per iteration: the exact front, gathered so
         # cross-shard edges form their messages against remote sources
-        cur_full = jax.lax.all_gather(c.cur, axis, axis=1, tiled=True)
+        with jax.named_scope("cqp.expand"):
+            cur_full = jax.lax.all_gather(c.cur, axis, axis=1, tiled=True)
         dst = _local_dst(g.dst, off, num_local)
         outd_local = jax.lax.dynamic_slice_in_dim(g.out_degree, off, num_local)
     v_ids = off + jnp.arange(num_local, dtype=jnp.int32)[None, :]
@@ -449,47 +453,60 @@ def _sweep_body(
     #    ``dirty`` is per-query [Q, V]: a δE batch dirties every query's row,
     #    a mid-stream register dirties only the new slot's.  Inactive slots
     #    (the session's free pool) are scheduled for no work at all.
-    sched = (c.frontier | dirty) & active[:, None]
+    with jax.named_scope("cqp.frontier"):
+        sched = (c.frontier | dirty) & active[:, None]
+    # live in-edges of the scheduled rows (MaintainStats.active_edges; the
+    # repaired rows' are counted once, after the loop).  Formed from the
+    # carried frontier behind a barrier: a reduction of `sched` itself fused
+    # with the `cur` select and moved the frontier gather's [Q, V] table out
+    # of the TPU's on-chip memory, 4% of a chunk (v5e, 589,824 vertices)
+    sched_edges = jnp.where(
+        (jax.lax.optimization_barrier(c.frontier) | dirty) & active[:, None],
+        g.in_degree[None, :],
+        0,
+    ).sum(dtype=jnp.float32)
 
     # -- recompute D_i (dense; `sched|repair` is the algorithmic work mask).
+    #    Each phase runs under a ``cqp.*`` name scope, which XLA keeps in
+    #    every op's ``op_name`` metadata (DESIGN.md §15).
     if cfg.mode == "vdc":
-        # Maintain J at iteration i before reading it: an edge's message
-        # changes when its source changed at i-1, or the edge itself (or a
-        # sibling in-edge of its target) was touched by δE.  ``join_mat``
-        # gates the store per slot: a slot whose Join differences are
-        # dropped completely (§4) writes nothing and recomputes its
-        # messages on demand — JOD inside a VDC engine.
-        live_msgs = edge_messages(cfg, cur_full, g)
-        jprev, _, jfound = ds.lookup_le(c.jstore, i)
-        j0 = edge_messages(cfg, init, g)  # implicit J from D_0
-        jprev = jnp.where(jfound, jprev, j0)
-        # NOTE: deliberately NOT masked by g.valid — a deleted edge must
-        # overwrite its stored message with the identity.
-        dirty_pad = jnp.concatenate(
-            [dirty, jnp.zeros((dirty.shape[0], 1), bool)], axis=1
-        )
-        jmat = join_mat[:, None]
-        jdirty = c.changed_prev[:, g.src] | dirty_pad[:, dst]
-        jwrite = jdirty & (live_msgs != jprev) & jmat
-        jstore, _, _ = ds.upsert(c.jstore, i, jwrite, live_msgs)
-        # VDC path: the aggregator *reads* the materialized J difference
-        # sets for materializing slots, the on-demand messages otherwise.
-        jval, _, jfound2 = ds.lookup_le(jstore, i)
-        msgs = jnp.where(jmat, jnp.where(jfound2, jval, j0), live_msgs)
-        new = aggregate(cfg, msgs, c.cur, g, dst=dst, num_segments=num_local)
-        jwritten = c.stats.jwritten + jwrite.sum(dtype=jnp.int32)
+        with jax.named_scope("cqp.expand"):
+            # Maintain J at iteration i before reading it: an edge's message
+            # changes when its source changed at i-1, or the edge itself (or a
+            # sibling in-edge of its target) was touched by δE.  ``join_mat``
+            # gates the store per slot: a slot whose Join differences are
+            # dropped completely (§4) writes nothing and recomputes its
+            # messages on demand — JOD inside a VDC engine.
+            live_msgs = edge_messages(cfg, cur_full, g)
+            jprev, _, jfound = ds.lookup_le(c.jstore, i)
+            j0 = edge_messages(cfg, init, g)  # implicit J from D_0
+            jprev = jnp.where(jfound, jprev, j0)
+            # NOTE: deliberately NOT masked by g.valid — a deleted edge must
+            # overwrite its stored message with the identity.
+            dirty_pad = jnp.concatenate(
+                [dirty, jnp.zeros((dirty.shape[0], 1), bool)], axis=1
+            )
+            jmat = join_mat[:, None]
+            jdirty = c.changed_prev[:, g.src] | dirty_pad[:, dst]
+            jwrite = jdirty & (live_msgs != jprev) & jmat
+            jstore, _, _ = ds.upsert(c.jstore, i, jwrite, live_msgs)
+            # VDC path: the aggregator *reads* the materialized J difference
+            # sets for materializing slots, the on-demand messages otherwise.
+            jval, _, jfound2 = ds.lookup_le(jstore, i)
+            msgs = jnp.where(jmat, jnp.where(jfound2, jval, j0), live_msgs)
+            new = aggregate(cfg, msgs, c.cur, g, dst=dst, num_segments=num_local)
+            jwritten = c.stats.jwritten + jwrite.sum(dtype=jnp.int32)
     else:
         jstore = c.jstore
         jwritten = c.stats.jwritten
         # backend="fused" realizes JOD's expand *inside* the megakernel;
         # VDC hands the aggregated `new` to the kernel (partial fusion).
-        new = (
-            None
-            if cfg.backend == "fused"
-            else ife_step(
-                cfg, cur_full, g, carry=c.cur, dst=dst, num_segments=num_local
-            )
-        )
+        new = None
+        if cfg.backend != "fused":
+            with jax.named_scope("cqp.expand"):
+                new = ife_step(
+                    cfg, cur_full, g, carry=c.cur, dst=dst, num_segments=num_local
+                )
 
     if cfg.backend == "fused":
         # -- the maintenance megakernel: ONE pallas_call per iteration fuses
@@ -499,151 +516,162 @@ def _sweep_body(
         #    calls the same library primitives as the stitched path below
         #    (expand_tile, ds.*, dr.select_to_drop, bloom.query), so results
         #    are bit-identical.
-        sr = cfg.semiring
-        kw: dict = {}
-        if new is None:
-            nq = cur_full.shape[0]
-            kw["states"] = jnp.concatenate(
-                [cur_full, jnp.full((nq, 1), sr.identity, cur_full.dtype)],
-                axis=1,
-            )
-            kw["nbr"] = g.nbr
-            kw["w"] = _ell_weights(cfg, g)
-            kw["kcarry"] = (
-                c.cur if sr.carry_prev else jnp.full_like(c.cur, sr.base)
-            )
-        else:
-            kw["new"] = new
-        if cfg.drop.enabled():
-            kw["degree"] = degree
-            kw["params"] = c.drop.params
-            if cfg.drop.mode == "det":
-                kw["det"] = c.drop.det
+        with jax.named_scope("cqp.fused_sweep"):
+            sr = cfg.semiring
+            kw: dict = {}
+            if new is None:
+                nq = cur_full.shape[0]
+                kw["states"] = jnp.concatenate(
+                    [cur_full, jnp.full((nq, 1), sr.identity, cur_full.dtype)],
+                    axis=1,
+                )
+                kw["nbr"] = g.nbr
+                kw["w"] = _ell_weights(cfg, g)
+                kw["kcarry"] = (
+                    c.cur if sr.carry_prev else jnp.full_like(c.cur, sr.base)
+                )
             else:
-                kw["bloom_bits"] = c.drop.flt.bits
-                kw["bloom_hashes"] = c.drop.flt.num_hashes
-        out = fused_sweep_lib.fused_sweep(
-            i,
-            off,
-            sched,
-            active,
-            c.cur,
-            c.cur_old,
-            c.stale_old,
-            c.dstore,
-            old_dstore,
-            semiring=sr.kernel_name,
-            hop_cap=sr.hop_cap,
-            block_v=cfg.ell_block_v,
-            drop_mode=cfg.drop.mode if cfg.drop.enabled() else "none",
-            interpret=resolve_interpret(cfg.interpret),
-            **kw,
-        )
+                kw["new"] = new
+            if cfg.drop.enabled():
+                kw["degree"] = degree
+                kw["params"] = c.drop.params
+                if cfg.drop.mode == "det":
+                    kw["det"] = c.drop.det
+                else:
+                    kw["bloom_bits"] = c.drop.flt.bits
+                    kw["bloom_hashes"] = c.drop.flt.num_hashes
+            out = fused_sweep_lib.fused_sweep(
+                i,
+                off,
+                sched,
+                active,
+                c.cur,
+                c.cur_old,
+                c.stale_old,
+                c.dstore,
+                old_dstore,
+                semiring=sr.kernel_name,
+                hop_cap=sr.hop_cap,
+                block_v=cfg.ell_block_v,
+                drop_mode=cfg.drop.mode if cfg.drop.enabled() else "none",
+                interpret=resolve_interpret(cfg.interpret),
+                **kw,
+            )
         dstore = ds.DiffStore(out.d_iters, out.d_vals, out.d_count)
         cur_next = out.cur
         old_i, stale = out.old, out.stale
         changed, repair = out.changed, out.repair
         to_store, to_drop, vanish = out.to_store, out.to_drop, out.vanish
-        drop_state = c.drop
-        if cfg.drop.enabled():
-            if cfg.drop.mode == "det":
-                # DroppedVT was maintained in VMEM; adopt the kernel's rows
-                # and fold the per-tile overflow/horizon partials back into
-                # the replicated scalars (sum/max are associative).
-                drop_state = drop_state._replace(
-                    det=ds.DiffStore(
-                        out.det_iters, c.drop.det.vals, out.det_count
-                    ),
-                    det_overflow=c.drop.det_overflow
-                    + out.det_overflow.sum(dtype=jnp.int32),
-                    max_iter=jnp.maximum(
-                        c.drop.max_iter, out.det_max_iter.max()
-                    ),
-                )
-            else:
-                # Bloom inserts stay outside the kernel (XLA scatter; the OR
-                # is idempotent so ordering is immaterial) — identical bits
-                # to the stitched register pair; unregister is a prob no-op.
-                drop_state = dr.register(drop_state, i, out.to_drop, v_offset=off)
-                drop_state = dr.register(
-                    drop_state, out.evicted_iter, out.evicted, v_offset=off
-                )
+        with jax.named_scope("cqp.drop"):
+            drop_state = c.drop
+            if cfg.drop.enabled():
+                if cfg.drop.mode == "det":
+                    # DroppedVT was maintained in VMEM; adopt the kernel's rows
+                    # and fold the per-tile overflow/horizon partials back into
+                    # the replicated scalars (sum/max are associative).
+                    drop_state = drop_state._replace(
+                        det=ds.DiffStore(
+                            out.det_iters, c.drop.det.vals, out.det_count
+                        ),
+                        det_overflow=c.drop.det_overflow
+                        + out.det_overflow.sum(dtype=jnp.int32),
+                        max_iter=jnp.maximum(
+                            c.drop.max_iter, out.det_max_iter.max()
+                        ),
+                    )
+                else:
+                    # Bloom inserts stay outside the kernel (XLA scatter; the OR
+                    # is idempotent so ordering is immaterial) — identical bits
+                    # to the stitched register pair; unregister is a prob no-op.
+                    drop_state = dr.register(drop_state, i, out.to_drop, v_offset=off)
+                    drop_state = dr.register(
+                        drop_state, out.evicted_iter, out.evicted, v_offset=off
+                    )
     else:
         # -- dropped change points at i must be recomputed to keep `cur`
         #    exact (AccessDᵢᵛWithDrops, forward form).  Prob-Drop may
         #    false-positive here → spurious but safe recompute.
-        dropped_here = (
-            dr.dropped_at(c.drop, i, num_local, v_offset=off)
-            if cfg.drop.enabled()
-            else jnp.zeros_like(sched)
-        )
-        repair = dropped_here & active[:, None] & ~sched
-
-        # -- pre-update trajectory at i (δ detection), from the frozen store.
-        old_has, old_val = ds.value_at(old_dstore, i)
-        old_i = jnp.where(old_has, old_val, c.cur_old)
-        # A dropped old change point leaves old_i stale until the next
-        # stored old point re-anchors it; stale scheduled vertices propagate
-        # conservatively.
-        stale = (c.stale_old | dropped_here) & ~old_has
-
-        changed = sched & ((new != old_i) | stale)
-
-        # -- new trajectory change point at i?  (vs exact D_{i-1} = cur)
-        want_point = sched & (new != c.cur)
-        has_cur, cur_stored_val = ds.value_at(c.dstore, i)
-
-        if cfg.drop.enabled():
-            to_drop = want_point & dr.select_to_drop(
-                c.drop.params, degree, q_ids, v_ids, i
+        with jax.named_scope("cqp.drop"):
+            dropped_here = (
+                dr.dropped_at(c.drop, i, num_local, v_offset=off)
+                if cfg.drop.enabled()
+                else jnp.zeros_like(sched)
             )
-            to_store = want_point & ~to_drop
-        else:
-            to_drop = jnp.zeros_like(want_point)
-            to_store = want_point
+            repair = dropped_here & active[:, None] & ~sched
 
-        dstore, evicted, evicted_iter = ds.upsert(c.dstore, i, to_store, new)
-        # one fused removal pass (each full remove_at rewrites the store):
-        #   · a dropped point at i that had a stored twin loses the twin
-        #   · a vanished change point (+/- pair cancelled) is deleted
-        vanish = sched & ~want_point & has_cur
-        dstore = ds.remove_at(dstore, i, (to_drop & has_cur) | vanish)
+        with jax.named_scope("cqp.store"):
+            # -- pre-update trajectory at i (δ detection), from the frozen
+            #    store.
+            old_has, old_val = ds.value_at(old_dstore, i)
+            old_i = jnp.where(old_has, old_val, c.cur_old)
+            # A dropped old change point leaves old_i stale until the next
+            # stored old point re-anchors it; stale scheduled vertices
+            # propagate conservatively.
+            stale = (c.stale_old | dropped_here) & ~old_has
 
-        drop_state = c.drop
-        if cfg.drop.enabled():
-            drop_state = dr.register(drop_state, i, to_drop, v_offset=off)
-            drop_state = dr.register(
-                drop_state, evicted_iter, evicted, v_offset=off
-            )
-            # a dropped record is stale once the point is stored or vanished
-            drop_state = dr.unregister(drop_state, i, to_store | vanish)
+            changed = sched & ((new != old_i) | stale)
+
+            # -- new trajectory change point at i?  (vs exact D_{i-1} = cur)
+            want_point = sched & (new != c.cur)
+            has_cur, cur_stored_val = ds.value_at(c.dstore, i)
+
+        with jax.named_scope("cqp.drop"):
+            if cfg.drop.enabled():
+                to_drop = want_point & dr.select_to_drop(
+                    c.drop.params, degree, q_ids, v_ids, i
+                )
+                to_store = want_point & ~to_drop
+            else:
+                to_drop = jnp.zeros_like(want_point)
+                to_store = want_point
+
+        with jax.named_scope("cqp.store"):
+            dstore, evicted, evicted_iter = ds.upsert(c.dstore, i, to_store, new)
+            # one fused removal pass (each full remove_at rewrites the store):
+            #   · a dropped point at i that had a stored twin loses the twin
+            #   · a vanished change point (+/- pair cancelled) is deleted
+            vanish = sched & ~want_point & has_cur
+            dstore = ds.remove_at(dstore, i, (to_drop & has_cur) | vanish)
+
+        with jax.named_scope("cqp.drop"):
+            drop_state = c.drop
+            if cfg.drop.enabled():
+                drop_state = dr.register(drop_state, i, to_drop, v_offset=off)
+                drop_state = dr.register(
+                    drop_state, evicted_iter, evicted, v_offset=off
+                )
+                # a dropped record is stale once the point is stored or
+                # vanished
+                drop_state = dr.unregister(drop_state, i, to_store | vanish)
 
         # -- advance the exact trajectory.
-        recompute = sched | repair
-        cur_next = jnp.where(
-            recompute, new, jnp.where(has_cur, cur_stored_val, c.cur)
-        )
+        with jax.named_scope("cqp.store"):
+            cur_next = jnp.where(
+                sched | repair, new, jnp.where(has_cur, cur_stored_val, c.cur)
+            )
 
     if cfg.drop.enabled() and axis is not None:
         # per-shard inserts merge back into the shared structures: OR the
         # Bloom bits (psum of bools), pmax the horizon anchor, psum the
         # overflow delta — all scalars/filters stay replicated.
-        if drop_state.flt is not None:
-            bits = jax.lax.psum(drop_state.flt.bits.astype(jnp.int32), axis) > 0
-            drop_state = drop_state._replace(flt=drop_state.flt._replace(bits))
-        drop_state = drop_state._replace(
-            det_overflow=c.drop.det_overflow
-            + jax.lax.psum(drop_state.det_overflow - c.drop.det_overflow, axis),
-            max_iter=jax.lax.pmax(drop_state.max_iter, axis),
+        with jax.named_scope("cqp.drop"):
+            if drop_state.flt is not None:
+                bits = jax.lax.psum(drop_state.flt.bits.astype(jnp.int32), axis) > 0
+                drop_state = drop_state._replace(flt=drop_state.flt._replace(bits))
+            drop_state = drop_state._replace(
+                det_overflow=c.drop.det_overflow
+                + jax.lax.psum(drop_state.det_overflow - c.drop.det_overflow, axis),
+                max_iter=jax.lax.pmax(drop_state.max_iter, axis),
+            )
+    with jax.named_scope("cqp.frontier"):
+        changed_full = (
+            changed
+            if axis is None
+            else jax.lax.all_gather(changed, axis, axis=1, tiled=True)
         )
-    changed_full = (
-        changed
-        if axis is None
-        else jax.lax.all_gather(changed, axis, axis=1, tiled=True)
-    )
-    frontier_next = (
-        push_frontier(changed_full, g, dst=dst, num_segments=num_local) | changed
-    )  # | changed: carry a changed vertex's own next value
+        frontier_next = (
+            push_frontier(changed_full, g, dst=dst, num_segments=num_local) | changed
+        )  # | changed: carry a changed vertex's own next value
 
     # per-iteration probe: iteration i lands in bin i-1 (clamped to the last
     # bin) so short sweeps read directly as a size-per-iteration series
@@ -658,6 +686,7 @@ def _sweep_body(
         dropped=c.stats.dropped + to_drop.sum(dtype=jnp.int32),
         jwritten=jwritten,
         det_overflow=c.stats.det_overflow,  # folded in after the loop
+        active_edges=c.stats.active_edges + sched_edges,
         sched_sizes=c.stats.sched_sizes.at[bin_i].add(
             sched.sum(dtype=jnp.int32)
         ),
@@ -772,7 +801,13 @@ def _maintain_core(
         stats=zeros_stats(),
     )
     c = jax.lax.while_loop(cond, body, c0)
-    stats = c.stats
+    # the repaired rows' live in-edges, from how often the sweep repaired
+    # each row: one pass here, not one an iteration
+    repaired = (c.repair_counts - state.repair_counts).astype(jnp.float32)
+    stats = c.stats._replace(
+        active_edges=c.stats.active_edges
+        + (repaired * g.in_degree[None, :]).sum()
+    )
     if axis is not None:
         # per-shard partial sums → global; iters_run is already replicated
         stats = stats._replace(
@@ -782,6 +817,7 @@ def _maintain_core(
             written=jax.lax.psum(stats.written, axis),
             removed=jax.lax.psum(stats.removed, axis),
             dropped=jax.lax.psum(stats.dropped, axis),
+            active_edges=jax.lax.psum(stats.active_edges, axis),
             jwritten=jax.lax.psum(stats.jwritten, axis),
             sched_sizes=jax.lax.psum(stats.sched_sizes, axis),
             frontier_sizes=jax.lax.psum(stats.frontier_sizes, axis),
@@ -928,43 +964,44 @@ def _batched_core_sharded(
     shard = jax.lax.axis_index(axis).astype(jnp.int32)
     off = shard * num_local
 
-    # edge-cell scatter: upd.slot is the linear ShardIndex cell (shard·C + pos)
-    slot = upd.slot - shard * es
-    slot = jnp.where((slot >= 0) & (slot < es), slot, es)  # foreign → dropped
-    src = g.src.at[slot].set(upd.src, mode="drop")
-    dst = g.dst.at[slot].set(upd.dst, mode="drop")
-    weight = g.weight.at[slot].set(upd.weight, mode="drop")
-    valid = g.valid.at[slot].set(upd.valid, mode="drop")
+    with jax.named_scope("cqp.edge_scatter"):
+        # edge-cell scatter: upd.slot is the linear ShardIndex cell (shard·C + pos)
+        slot = upd.slot - shard * es
+        slot = jnp.where((slot >= 0) & (slot < es), slot, es)  # foreign → dropped
+        src = g.src.at[slot].set(upd.src, mode="drop")
+        dst = g.dst.at[slot].set(upd.dst, mode="drop")
+        weight = g.weight.at[slot].set(upd.weight, mode="drop")
+        valid = g.valid.at[slot].set(upd.valid, mode="drop")
 
-    # degrees recomputed from the (distributed) edge list: out-degrees need a
-    # cross-shard psum (any shard may hold out-edges of any source); in-
-    # degrees are a shard-local property of the owned destination block.
-    live = valid.astype(jnp.int32)
-    out_degree = jax.lax.psum(
-        jax.ops.segment_sum(live, src, num_segments=v), axis
-    )
-    dst_l = _local_dst(dst, off, num_local)
-    in_degree = jax.ops.segment_sum(live, dst_l, num_segments=num_local)
-
-    nbr, ell_w = g.nbr, g.ell_w
-    if cfg.backend in ("ell", "fused"):
-        row = upd.ell_row - off
-        row = jnp.where((row >= 0) & (row < num_local), row, num_local)
-        nbr = nbr.at[row, upd.ell_col].set(upd.ell_nbr, mode="drop")
-        ell_w = ell_w.at[row, upd.ell_col].set(upd.ell_w, mode="drop")
-    g2 = GraphArrays(src, dst, weight, valid, out_degree, in_degree, nbr, ell_w)
-
-    dv = upd.dirty_v - off
-    dv = jnp.where((dv >= 0) & (dv < num_local), dv, num_local)
-    dirty = jnp.zeros(num_local + 1, bool).at[dv].set(True)[:num_local]
-    if cfg.weight_from_degree:
-        # outdeg(u) changed → every out-message of u retunes (δE dirty rule)
-        tsrc = jnp.zeros(v + 1, bool).at[upd.touched_src].set(True)[:v]
-        hit = (tsrc[src] & valid).astype(jnp.int32)
-        dirty = dirty | (
-            jax.ops.segment_max(hit, dst_l, num_segments=num_local) > 0
+        # degrees recomputed from the (distributed) edge list: out-degrees need a
+        # cross-shard psum (any shard may hold out-edges of any source); in-
+        # degrees are a shard-local property of the owned destination block.
+        live = valid.astype(jnp.int32)
+        out_degree = jax.lax.psum(
+            jax.ops.segment_sum(live, src, num_segments=v), axis
         )
-    dirty = jnp.broadcast_to(dirty[None, :], (cfg.num_queries, num_local))
+        dst_l = _local_dst(dst, off, num_local)
+        in_degree = jax.ops.segment_sum(live, dst_l, num_segments=num_local)
+
+        nbr, ell_w = g.nbr, g.ell_w
+        if cfg.backend in ("ell", "fused"):
+            row = upd.ell_row - off
+            row = jnp.where((row >= 0) & (row < num_local), row, num_local)
+            nbr = nbr.at[row, upd.ell_col].set(upd.ell_nbr, mode="drop")
+            ell_w = ell_w.at[row, upd.ell_col].set(upd.ell_w, mode="drop")
+        g2 = GraphArrays(src, dst, weight, valid, out_degree, in_degree, nbr, ell_w)
+
+        dv = upd.dirty_v - off
+        dv = jnp.where((dv >= 0) & (dv < num_local), dv, num_local)
+        dirty = jnp.zeros(num_local + 1, bool).at[dv].set(True)[:num_local]
+        if cfg.weight_from_degree:
+            # outdeg(u) changed → every out-message of u retunes (δE dirty rule)
+            tsrc = jnp.zeros(v + 1, bool).at[upd.touched_src].set(True)[:v]
+            hit = (tsrc[src] & valid).astype(jnp.int32)
+            dirty = dirty | (
+                jax.ops.segment_max(hit, dst_l, num_segments=num_local) > 0
+            )
+        dirty = jnp.broadcast_to(dirty[None, :], (cfg.num_queries, num_local))
 
     new_state, stats = _maintain_core(cfg, state, g2, dirty, axis=axis)
     return new_state, g2, stats
@@ -1158,27 +1195,28 @@ def batched_step(
     trip, no buffer churn); host work per chunk is an O(B) encode.
     """
     v = cfg.num_vertices
-    src = g.src.at[upd.slot].set(upd.src, mode="drop")
-    dst = g.dst.at[upd.slot].set(upd.dst, mode="drop")
-    weight = g.weight.at[upd.slot].set(upd.weight, mode="drop")
-    valid = g.valid.at[upd.slot].set(upd.valid, mode="drop")
-    # degrees recomputed from the edge list — O(E) on-device, far below one
-    # sweep iteration, and immune to host/device drift
-    live = valid.astype(jnp.int32)
-    out_degree = jax.ops.segment_sum(live, src, num_segments=v)
-    in_degree = jax.ops.segment_sum(live, dst, num_segments=v)
-    nbr, ell_w = g.nbr, g.ell_w
-    if cfg.backend in ("ell", "fused"):
-        nbr = nbr.at[upd.ell_row, upd.ell_col].set(upd.ell_nbr, mode="drop")
-        ell_w = ell_w.at[upd.ell_row, upd.ell_col].set(upd.ell_w, mode="drop")
-    g2 = GraphArrays(src, dst, weight, valid, out_degree, in_degree, nbr, ell_w)
+    with jax.named_scope("cqp.edge_scatter"):
+        src = g.src.at[upd.slot].set(upd.src, mode="drop")
+        dst = g.dst.at[upd.slot].set(upd.dst, mode="drop")
+        weight = g.weight.at[upd.slot].set(upd.weight, mode="drop")
+        valid = g.valid.at[upd.slot].set(upd.valid, mode="drop")
+        # degrees recomputed from the edge list — O(E) on-device, far below one
+        # sweep iteration, and immune to host/device drift
+        live = valid.astype(jnp.int32)
+        out_degree = jax.ops.segment_sum(live, src, num_segments=v)
+        in_degree = jax.ops.segment_sum(live, dst, num_segments=v)
+        nbr, ell_w = g.nbr, g.ell_w
+        if cfg.backend in ("ell", "fused"):
+            nbr = nbr.at[upd.ell_row, upd.ell_col].set(upd.ell_nbr, mode="drop")
+            ell_w = ell_w.at[upd.ell_row, upd.ell_col].set(upd.ell_w, mode="drop")
+        g2 = GraphArrays(src, dst, weight, valid, out_degree, in_degree, nbr, ell_w)
 
-    dirty = jnp.zeros(v + 1, bool).at[upd.dirty_v].set(True)[:v]
-    if cfg.weight_from_degree:
-        # outdeg(u) changed → every out-message of u retunes (δE dirty rule)
-        tsrc = jnp.zeros(v + 1, bool).at[upd.touched_src].set(True)[:v]
-        hit = (tsrc[g2.src] & g2.valid).astype(jnp.int32)
-        dirty = dirty | (jax.ops.segment_max(hit, g2.dst, num_segments=v) > 0)
+        dirty = jnp.zeros(v + 1, bool).at[upd.dirty_v].set(True)[:v]
+        if cfg.weight_from_degree:
+            # outdeg(u) changed → every out-message of u retunes (δE dirty rule)
+            tsrc = jnp.zeros(v + 1, bool).at[upd.touched_src].set(True)[:v]
+            hit = (tsrc[g2.src] & g2.valid).astype(jnp.int32)
+            dirty = dirty | (jax.ops.segment_max(hit, g2.dst, num_segments=v) > 0)
 
     new_state, stats = maintain(cfg, state, g2, _dirty_2d(cfg, dirty))
     return new_state, g2, stats
@@ -1186,6 +1224,18 @@ def batched_step(
 
 def _sum_stats(a: MaintainStats, b: MaintainStats) -> MaintainStats:
     return MaintainStats(*(x + y for x, y in zip(a, b)))
+
+
+def _named(name: str, fn, *static):
+    """``fn`` with its leading ``static`` arguments bound, as a function
+    called ``name``: ``jax.jit`` names the program after it, where a
+    ``functools.partial`` shows as ``jit__unknown``."""
+
+    def call(*args):
+        return fn(*static, *args)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def _span_stats(stats: MaintainStats | None) -> dict:
@@ -1310,21 +1360,28 @@ class DiffIFE:
             self._run_counted(np.ones(cfg.num_vertices, dtype=bool))
 
     def _build_dispatch(self) -> None:
-        """(Re)jit the two dispatch paths for the current static config."""
+        """(Re)jit the two dispatch paths for the current static config.
+
+        Each program is jitted under a name of its own, which names its
+        module in the device trace (``jit_cqp_chunk_step``, ...)."""
         if self.num_shards > 1:
-            self._maintain = jax.jit(partial(maintain_sharded, self.cfg, self.mesh))
+            static = (self.cfg, self.mesh)
+            self._maintain = jax.jit(
+                _named("cqp_maintain_sharded", maintain_sharded, *static)
+            )
             self._step = jax.jit(
-                partial(batched_step_sharded, self.cfg, self.mesh),
+                _named("cqp_chunk_step_sharded", batched_step_sharded, *static),
                 donate_argnums=(0, 1),
             )
         else:
-            self._maintain = jax.jit(partial(maintain, self.cfg))
+            self._maintain = jax.jit(_named("cqp_maintain", maintain, self.cfg))
             self._step = jax.jit(
-                partial(batched_step, self.cfg), donate_argnums=(0, 1)
+                _named("cqp_chunk_step", batched_step, self.cfg),
+                donate_argnums=(0, 1),
             )
         # governor reclamation primitive; slot is traced so every rewrite of
         # any slot reuses one compiled program
-        self._shed = jax.jit(partial(shed_slot, self.cfg))
+        self._shed = jax.jit(_named("cqp_shed", shed_slot, self.cfg))
 
     # ------------------------------------------------------------ device views
     def _device_graph(self, snap: GraphSnapshot) -> GraphArrays:
@@ -1441,7 +1498,8 @@ class DiffIFE:
             self.g = self._device_graph(snap)
             touched = [(u, v) for (_k, _s, u, v, _w) in ops]
             self._run_counted(self._dirty_mask(touched, snap))
-            sp.set(num_updates=len(ops), **_span_stats(self.last_stats))
+            if sp.live:
+                sp.set(num_updates=len(ops), **_span_stats(self.last_stats))
         return self.last_stats
 
     def _full_sweep_fallback(self, ops, total: MaintainStats) -> MaintainStats:
@@ -1465,6 +1523,11 @@ class DiffIFE:
         ``batch_capacity``); per chunk ONE jitted call scatters the edge
         slots, refreshes degrees, builds the dirty mask on device and runs
         the maintenance sweep.  Returns the cumulative stats over the log.
+
+        Spans, per chunk: ``ingest`` (host graph update, shard/ELL writes,
+        encode) and ``dispatch`` (the enqueue of the asynchronous chunk
+        program, not its device time); per log: ``sync``, the one wait for
+        the device, when the summed stats come to the host.
         """
         b = int(batch_size if batch_size is not None else self.batch_capacity)
         updates = list(updates)
@@ -1478,53 +1541,55 @@ class DiffIFE:
             shards=self.num_shards,
         ) as outer:
             for lo in range(0, len(updates), b):
-                ops = self.graph.apply_batch_resolved(updates[lo : lo + b])
+                with obs_trace.span(
+                    "ingest", "ingest", pid="engine:dense", chunk_lo=lo
+                ):
+                    ops = self.graph.apply_batch_resolved(updates[lo : lo + b])
+                    upd = self._ingest_chunk(ops, b) if ops else None
                 if not ops:
                     continue
-                shard_writes = None
-                if self.num_shards > 1:
-                    shard_writes = self._shard_sync(ops)
-                    if shard_writes is None:
-                        # a shard's cells overflowed: layout regrown (jstore
-                        # rows permuted), one full-view sweep for this chunk
-                        total = self._full_sweep_fallback(ops, total)
-                        continue
-                ell_writes: list = []
-                if self.cfg.backend in ("ell", "fused"):
-                    try:
-                        ell_writes = self._ell_index.writes_for(ops)
-                    except EllOverflow:
-                        # a vertex outran the fixed D: grow geometrically and
-                        # fall back to a full-view sweep (one re-trace)
-                        self._ell_width = max(8, self._ell_width * 2)
-                        total = self._full_sweep_fallback(ops, total)
-                        continue
-                upd = self._encode_chunk(ops, ell_writes, b, shard_writes)
-                # the sweep span covers one chunk's maintenance sweep; the
-                # nested dispatch span is the jitted call itself.  Per-chunk
-                # stats stay on device (one host sync per log) — the outer
-                # update_batch span carries the cumulative counters.
+                if upd is None:
+                    # a shard's cells or a vertex's ELL row overflowed: the
+                    # layout was regrown, one full-view sweep for this chunk
+                    total = self._full_sweep_fallback(ops, total)
+                    continue
                 with obs_trace.span(
-                    "sweep", "sweep", pid="engine:dense",
-                    chunk_lo=lo, num_ops=len(ops),
+                    "dispatch",
+                    "dispatch",
+                    pid="engine:dense",
+                    chunk_lo=lo,
+                    num_ops=len(ops),
+                    backend=self.cfg.backend,
                 ):
-                    with obs_trace.span(
-                        "kernel_dispatch",
-                        "kernel_dispatch",
-                        pid="engine:dense",
-                        chunk_lo=lo,
-                        num_ops=len(ops),
-                        backend=self.cfg.backend,
-                    ):
-                        self.state, self.g, stats = self._step(
-                            self.state, self.g, upd
-                        )
+                    self.state, self.g, stats = self._step(self.state, self.g, upd)
                     # accumulate on device — one host sync per log, not per chunk
                     total = _sum_stats(total, stats)
-            self.last_stats = jax.tree.map(jax.device_get, total)
-            outer.set(**_span_stats(self.last_stats))
+            with obs_trace.span("sync", "sync", pid="engine:dense"):
+                self.last_stats = jax.tree.map(jax.device_get, total)
+            if outer.live:
+                outer.set(**_span_stats(self.last_stats))
         self._sched_total += int(self.last_stats.scheduled)
         return self.last_stats
+
+    def _ingest_chunk(self, ops, b: int) -> UpdateBatch | None:
+        """The device encoding of one chunk's resolved ops, with the shard
+        and ELL mirrors brought up to date; None when a layout overflowed
+        and was regrown, so the chunk needs a full-view sweep."""
+        shard_writes = None
+        if self.num_shards > 1:
+            shard_writes = self._shard_sync(ops)
+            if shard_writes is None:
+                return None  # jstore rows already permuted to the new cells
+        ell_writes: list = []
+        if self.cfg.backend in ("ell", "fused"):
+            try:
+                ell_writes = self._ell_index.writes_for(ops)
+            except EllOverflow:
+                # a vertex outran the fixed D: grow geometrically (one
+                # re-trace on the full-view sweep)
+                self._ell_width = max(8, self._ell_width * 2)
+                return None
+        return self._encode_chunk(ops, ell_writes, b, shard_writes)
 
     def _encode_chunk(self, ops, ell_writes, b: int, shard_writes=None) -> UpdateBatch:
         """Host O(B) encode of resolved ops → fixed-shape UpdateBatch."""

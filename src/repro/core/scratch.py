@@ -72,6 +72,9 @@ def scratch_run(
     stats = zeros_stats()._replace(
         iters_run=iters,
         scheduled=iters * per_iter,
+        # every row reruns every iteration, reading every live in-edge
+        active_edges=(iters * q).astype(jnp.float32)
+        * g.valid.sum(dtype=jnp.float32),
         sched_sizes=sched_sizes,
     )
     return final, stats

@@ -770,14 +770,15 @@ class CQPSession:
         thread, so concurrent readers never observe a half-applied δE
         chunk (DESIGN.md §14)."""
         out: dict[int, np.ndarray] = {}
-        if self._impl is not None:
-            out = {
-                qid: np.array(self._impl.answers_row(slot), copy=True)
-                for qid, slot in self._handles.items()
-                if qid not in self._internal
-            }
-        if self._planner is not None:
-            out.update(self._planner.answers_snapshot())
+        with obs_trace.span("snapshot", "snapshot", pid="session"):
+            if self._impl is not None:
+                out = {
+                    qid: np.array(self._impl.answers_row(slot), copy=True)
+                    for qid, slot in self._handles.items()
+                    if qid not in self._internal
+                }
+            if self._planner is not None:
+                out.update(self._planner.answers_snapshot())
         return out
 
     def nbytes(self) -> int:

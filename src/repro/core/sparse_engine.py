@@ -73,6 +73,7 @@ class SparseDiffIFE:
         self._free: list[int] = []
         self._num_slots = 0
         self.work = 0  # aggregator re-runs (the paper's work metric)
+        self.edges_read = 0  # in-edges those re-runs read (active_edges)
         self.work_per_slot: dict[int, int] = {}  # per-query recompute signal
         # governor scratch fallback: slots whose difference index was dropped
         # entirely — answers re-executed from scratch per batch (slot → row)
@@ -202,6 +203,7 @@ class SparseDiffIFE:
         for _ in range(self.max_iters):
             nxt = vals.copy()
             for v, ins in self.in_nbrs.items():
+                self.edges_read += len(ins)
                 best = nxt[v]
                 for u, w in ins.items():
                     cand = self._msg(q, float(vals[u]), w)
@@ -244,7 +246,9 @@ class SparseDiffIFE:
         self.work += 1
         self.work_per_slot[q] = self.work_per_slot.get(q, 0) + 1
         best = self._value_at(q, v, i - 1)  # carry (includes implicit init)
-        for u, w in self.in_nbrs.get(v, {}).items():
+        ins = self.in_nbrs.get(v, {})
+        self.edges_read += len(ins)
+        for u, w in ins.items():
             cand = self._msg(q, self._value_at(q, u, i - 1), w)
             if cand < best:
                 best = cand
@@ -322,6 +326,7 @@ class SparseDiffIFE:
 
         iters_max = 0
         scheduled = changed = repairs = written = removed = 0
+        edges0 = self.edges_read
         sched_sizes = np.zeros(ITER_TRACE, np.int64)
         frontier_sizes = np.zeros(ITER_TRACE, np.int64)
         sweep = obs_trace.span(
@@ -384,6 +389,7 @@ class SparseDiffIFE:
                 dropped=z(0),
                 jwritten=z(0),
                 det_overflow=z(0),
+                active_edges=np.float32(self.edges_read - edges0),
                 sched_sizes=sched_sizes.astype(np.int32),
                 frontier_sizes=frontier_sizes.astype(np.int32),
             )

@@ -26,7 +26,6 @@ from repro.obs.trace import (
     set_tracer,
     span,
     instant,
-    counter_event,
 )
 
 __all__ = [
@@ -41,5 +40,4 @@ __all__ = [
     "set_tracer",
     "span",
     "instant",
-    "counter_event",
 ]

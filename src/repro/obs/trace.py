@@ -4,20 +4,27 @@ Design constraints, in order:
 
 1. **Zero cost when off.**  The disabled path allocates nothing: ``span()``
    returns a module-level singleton whose ``__enter__``/``__exit__`` are
-   empty, and ``instant``/``counter_event`` return before touching the
-   clock.  The only per-call overhead is one attribute load and one branch.
+   empty, and ``instant`` returns before touching the clock.  The only
+   per-call overhead is one attribute load and one branch.
 2. **Bounded memory when on.**  Events land in a ``collections.deque`` with
    ``maxlen`` (drop-oldest).  A long-running server with tracing enabled
    holds at most ``capacity`` events; ``dropped_events`` counts the loss so
    an exported trace is honest about truncation.
 3. **Monotonic time.**  ``time.perf_counter_ns`` for both timestamps and
    durations — wall-clock steps (NTP) never tear a span.
+4. **The device trace's clock, on request.**  ``Tracer(profiler=True)``
+   also opens a ``jax.profiler.TraceAnnotation`` named ``cqp.<name>`` for
+   every live span, so a ``jax.profiler`` trace holds the program's spans
+   beside the device's ops and an idle gap can be put down to one of them.
 
 Span taxonomy (``cat`` in the exported trace; see DESIGN.md §15):
 
 - ``update_batch``   — one host-level δE ingestion (``apply_updates[_batched]``)
-- ``sweep``          — one maintenance sweep dispatch (stats in ``args``)
-- ``kernel_dispatch``— one jitted chunk step inside a batched ingestion
+- ``sweep``          — one host-path maintenance sweep (stats in ``args``)
+- ``ingest``         — one chunk's host ingest: graph update, layout writes, encode
+- ``dispatch``       — the enqueue of one chunk program (not its device time)
+- ``sync``           — the host's wait for the device at the end of a log
+- ``snapshot``       — one ``answers_snapshot`` read of every query's answers
 - ``repair``         — repair-on-access work (reassembly / scratch fallback)
 - ``governor``       — shed / ladder-escalation actions
 - ``checkpoint``     — checkpoint write / restore
@@ -32,9 +39,9 @@ The exported file is the Chrome Trace Event Format JSON object form::
 
     {"traceEvents": [...], "displayTimeUnit": "ms"}
 
-with ``ph: "X"`` complete events (``ts``/``dur`` in microseconds),
-``ph: "i"`` instants, and ``ph: "C"`` counter samples — loadable directly
-in https://ui.perfetto.dev or ``chrome://tracing``.
+with ``ph: "X"`` complete events (``ts``/``dur`` in microseconds) and
+``ph: "i"`` instants — loadable directly in https://ui.perfetto.dev or
+``chrome://tracing``.
 """
 
 from __future__ import annotations
@@ -52,7 +59,6 @@ __all__ = [
     "set_tracer",
     "span",
     "instant",
-    "counter_event",
 ]
 
 DEFAULT_CAPACITY = 65536
@@ -62,6 +68,7 @@ class _NullSpan:
     """Singleton no-op context manager: the disabled-tracer fast path."""
 
     __slots__ = ()
+    live = False  # callers build costly ``set`` args only for a live span
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -76,10 +83,21 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _trace_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` (imported on first use,
+    so the tracer itself needs no JAX)."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """A live complete-event ('X') span.  Created only when tracing is on."""
 
-    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0", "_ann")
+    live = True
 
     def __init__(
         self,
@@ -97,6 +115,7 @@ class _Span:
         self.tid = tid
         self.args = args
         self._t0 = 0
+        self._ann = None
 
     def set(self, **kwargs: Any) -> "_Span":
         """Attach attribution after the fact (e.g. sweep stats on exit)."""
@@ -107,11 +126,15 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        if self._tracer.profiler:
+            self._ann = _trace_annotation(f"cqp.{self.name}")
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: object) -> None:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._tracer._emit(
             {
                 "name": self.name,
@@ -132,10 +155,21 @@ class Tracer:
     Thread-safe: the serving tier records from executor threads; deque
     appends are atomic under the GIL but export snapshots take the lock so
     a concurrent flush never sees a torn buffer.
+
+    With ``profiler=True`` every live span is mirrored into the
+    ``jax.profiler`` trace as ``cqp.<name>`` (no args in the name), on the
+    device trace's clock; the ring buffer records it as before.
     """
 
-    def __init__(self, capacity: int = DEFAULT_CAPACITY, enabled: bool = True):
+    def __init__(
+        self,
+        capacity: int = DEFAULT_CAPACITY,
+        enabled: bool = True,
+        *,
+        profiler: bool = False,
+    ):
         self.enabled = bool(enabled)
+        self.profiler = bool(profiler)
         self.capacity = int(capacity)
         self._buf: deque[dict[str, Any]] = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
@@ -181,28 +215,6 @@ class Tracer:
                 "pid": pid,
                 "tid": tid,
                 "args": args,
-            }
-        )
-
-    def counter(
-        self,
-        name: str,
-        values: dict[str, float],
-        *,
-        pid: str = "repro",
-        tid: str | int = 0,
-    ) -> None:
-        """Chrome-trace 'C' sample: Perfetto renders a stacked counter track."""
-        if not self.enabled:
-            return
-        self._emit(
-            {
-                "name": name,
-                "ph": "C",
-                "ts": (time.perf_counter_ns() - self._epoch_ns) / 1e3,
-                "pid": pid,
-                "tid": tid,
-                "args": values,
             }
         )
 
@@ -272,12 +284,6 @@ def instant(name: str, cat: str = "", **kw: Any) -> None:
     t = _default
     if t.enabled:
         t.instant(name, cat, **kw)
-
-
-def counter_event(name: str, values: dict[str, float], **kw: Any) -> None:
-    t = _default
-    if t.enabled:
-        t.counter(name, values, **kw)
 
 
 def validate_chrome_trace(trace: dict[str, Any]) -> list[str]:

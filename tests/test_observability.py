@@ -6,8 +6,9 @@ DESIGN.md §15.  Covers:
   Chrome-trace export that passes the structural validator;
 * the typed metrics registry — counters/gauges/histograms, label series,
   JSON snapshot, Prometheus text exposition;
-* span coverage end to end — sweep/kernel-dispatch/update-batch spans from
-  the engines, governor escalation spans, checkpoint spans;
+* span coverage end to end — update-batch/ingest/dispatch/sync/snapshot
+  spans from the engines and the session, their mirror on the
+  ``jax.profiler`` trace, governor escalation spans, checkpoint spans;
 * cross-engine ``MaintainStats`` parity — dense/host/scratch emit the same
   stat keys, zero-filled where a counter is structurally absent;
 * Bloom probe math — the analytic FP estimate vs brute-force membership
@@ -86,13 +87,15 @@ def test_disabled_tracer_is_zero_allocation_noop():
     with s1 as sp:
         sp.set(anything=1)  # no-op, no error
     obs_trace.instant("evt", "sweep")
-    obs_trace.counter_event("c", {"v": 1})
-    assert obs_trace.get_tracer().events() == []
+    off = obs_trace.Tracer(enabled=False, profiler=True)
+    assert off.span("c", "sweep") is obs_trace.NULL_SPAN
+    assert not obs_trace.NULL_SPAN.live
+    assert obs_trace.get_tracer().events() == off.events() == []
 
 
 def test_span_records_duration_nesting_and_args(tracer):
     with obs_trace.span("outer", "update_batch", pid="engine:test", tid=3, a=1) as sp:
-        with obs_trace.span("inner", "kernel_dispatch", pid="engine:test"):
+        with obs_trace.span("inner", "dispatch", pid="engine:test"):
             pass
         sp.set(b=2)
     evs = tracer.events()
@@ -120,7 +123,8 @@ def test_chrome_trace_export_validates(tracer, tmp_path):
     with obs_trace.span("sweep", "sweep", pid="engine:dense", tid=0, n=3):
         pass
     tracer.instant("shed", "admission", pid="serving", tid="t0")
-    tracer.counter("queue", {"depth": 7})
+    with tracer.span("ingest", "ingest", pid="engine:dense", chunk_lo=0):
+        pass
     out = tmp_path / "trace.json"
     n = tracer.export(str(out))
     payload = json.loads(out.read_text())
@@ -203,10 +207,15 @@ def test_dense_batched_emits_sweep_and_kernel_dispatch_spans(tracer):
     s = _session(initial, "dense", batch_capacity=4)
     s.register(qplan.sssp(0, max_iters=MAX_ITERS))
     s.apply_updates_batched(log, batch_size=4)
+    s.answers_snapshot()
     by_cat: dict[str, list] = {}
     for e in tracer.events():
         by_cat.setdefault(e["cat"], []).append(e)
-    assert {"update_batch", "sweep", "kernel_dispatch"} <= set(by_cat)
+    assert {"update_batch", "ingest", "dispatch", "sync", "snapshot"} <= set(by_cat)
+    # one ingest and one dispatch span a chunk, one sync a log
+    chunks = -(-len(log) // 4)
+    assert len(by_cat["ingest"]) == len(by_cat["dispatch"]) == chunks
+    assert len(by_cat["sync"]) == 1
     # session- and engine-level ingestion spans nest under the same cat
     pids = {e["pid"] for e in by_cat["update_batch"]}
     assert {"session", "engine:dense"} <= pids
@@ -214,7 +223,12 @@ def test_dense_batched_emits_sweep_and_kernel_dispatch_spans(tracer):
     assert outer["args"]["iters_run"] >= 1
     # the per-iteration probe series rides on the update_batch span
     assert len(outer["args"]["sched_sizes"]) >= 1
-    assert by_cat["kernel_dispatch"][0]["args"]["backend"] == "coo"
+    assert by_cat["dispatch"][0]["args"]["backend"] == "coo"
+    # ingest, dispatch and sync nest inside the engine's update_batch span
+    t0, t1 = outer["ts"], outer["ts"] + outer["dur"]
+    for cat in ("ingest", "dispatch", "sync"):
+        for e in by_cat[cat]:
+            assert t0 <= e["ts"] and e["ts"] + e["dur"] <= t1 + 1e-3, cat
 
 
 def test_governor_escalation_emits_governor_spans(tracer):

@@ -412,10 +412,13 @@ _SMOKE = textwrap.dedent(
     np.testing.assert_array_equal(a.answers(), b.answers())
     log = [(2, 9, 0, 1.0, +1), (0, 1, 0, 1.0, -1), (4, 0, 0, 3.0, +1),
            (6, 7, 0, 1.0, -1)]
-    a.apply_updates_batched(log, batch_size=2)
-    b.apply_updates_batched(log, batch_size=2)
+    sa = a.apply_updates_batched(log, batch_size=2)
+    sb = b.apply_updates_batched(log, batch_size=2)
     np.testing.assert_array_equal(a.answers(), b.answers())
     assert sum(b.nbytes_per_device()) == b.nbytes() == a.nbytes()
+    # the shards' counters sum to the one-device sweep's
+    assert int(sb.scheduled) == int(sa.scheduled)
+    assert float(sb.active_edges) == float(sa.active_edges) > 0
     print("SHARDED-SMOKE-OK")
     """
 )
