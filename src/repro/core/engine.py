@@ -63,6 +63,13 @@ from repro.core.graph import (
     ShardIndex,
     ShardOverflow,
 )
+from repro.core.segments import (
+    EdgeOrder,
+    edge_order,
+    pack_bits,
+    sorted_segment_reduce,
+    unpack_bits,
+)
 from repro.core.semiring import Semiring, reduce_pair
 from repro.kernels.ell_spmv import ell_spmv
 from repro.kernels.interpret import resolve_interpret
@@ -251,7 +258,11 @@ def aggregate(
         seg = jax.vmap(lambda m: jax.ops.segment_min(m, dst, num_segments=v))
     else:
         seg = jax.vmap(lambda m: jax.ops.segment_sum(m, dst, num_segments=v))
-    agg = seg(msgs)
+    return _with_carry(sr, seg(msgs), cur)
+
+
+def _with_carry(sr: Semiring, agg: Array, cur: Array) -> Array:
+    """D_i from the reduced messages and D_{i-1}."""
     if sr.carry_prev:
         return reduce_pair(sr, agg, cur)
     return jnp.float32(sr.base) + agg
@@ -303,18 +314,30 @@ def ife_step(
     carry: Array | None = None,
     dst: Array | None = None,
     num_segments: int | None = None,
+    order: EdgeOrder | None = None,
 ) -> Array:
     """One exact IFE step D_{i-1} → D_i (join recomputed — the JOD path).
 
     ``cur`` is the full [Q, V] front; under the sharded sweep the optional
     ``carry``/``dst``/``num_segments`` restrict the output to the local
-    vertex partition.
+    vertex partition.  With the sweep's destination ``order`` a min
+    semiring reduces each in-edge run in order (exact in any order); a sum
+    keeps the unsorted segment sum, whose rounding the order would change.
     """
     if cfg.backend in ("ell", "fused"):
         # the fused backend carries the same blocked-ELL adjacency; the
         # standalone expand (reassembly/repair paths) is bit-identical to
         # the megakernel's in-kernel tile (shared ``expand_tile``)
         return ell_step(cfg, cur, g, carry=carry)
+    sr = cfg.semiring
+    if order is not None and sr.reduce == "min":
+        agg = sorted_segment_reduce(
+            lambda src, w: sr.msg(cur[:, src], w[None, :]),
+            order,
+            jnp.minimum,
+            sr.identity,
+        )
+        return _with_carry(sr, agg, cur if carry is None else carry)
     return aggregate(
         cfg,
         edge_messages(cfg, cur, g),
@@ -325,23 +348,18 @@ def ife_step(
     )
 
 
-def push_frontier(
-    changed: Array,
-    g: GraphArrays,
-    *,
-    dst: Array | None = None,
-    num_segments: int | None = None,
-) -> Array:
+def push_frontier(changed: Array, order: EdgeOrder) -> Array:
     """Out-neighbour mask of changed vertices (δD direct rule).
 
     ``changed`` spans the full vertex axis (sources are global); the output
-    covers ``num_segments`` destinations (the local partition when sharded).
+    covers ``order``'s destinations (the local partition when sharded): the
+    OR over each one's in-edge run, the query rows packed 32 to a word.
     """
-    dst = g.dst if dst is None else dst
-    v = changed.shape[-1] if num_segments is None else num_segments
-    hit = (changed[:, g.src] & g.valid[None, :]).astype(jnp.int32)
-    out = jax.vmap(lambda h: jax.ops.segment_max(h, dst, num_segments=v))(hit)
-    return out > 0
+    words = pack_bits(changed)
+    hits = sorted_segment_reduce(
+        lambda src, _: words[:, src], order, jnp.bitwise_or, 0
+    )
+    return unpack_bits(hits, changed.shape[0])
 
 
 def _local_dst(dst: Array, off: Array, num_local: int) -> Array:
@@ -427,6 +445,7 @@ def _sweep_body(
     old_dstore: ds.DiffStore,
     active: Array,
     join_mat: Array | None,
+    order: EdgeOrder,
     axis: str | None,
     c: _Carry,
 ) -> _Carry:
@@ -505,7 +524,13 @@ def _sweep_body(
         if cfg.backend != "fused":
             with jax.named_scope("cqp.expand"):
                 new = ife_step(
-                    cfg, cur_full, g, carry=c.cur, dst=dst, num_segments=num_local
+                    cfg,
+                    cur_full,
+                    g,
+                    carry=c.cur,
+                    dst=dst,
+                    num_segments=num_local,
+                    order=order,
                 )
 
     if cfg.backend == "fused":
@@ -670,7 +695,7 @@ def _sweep_body(
             else jax.lax.all_gather(changed, axis, axis=1, tiled=True)
         )
         frontier_next = (
-            push_frontier(changed_full, g, dst=dst, num_segments=num_local) | changed
+            push_frontier(changed_full, order) | changed
         )  # | changed: carry a changed vertex's own next value
 
     # per-iteration probe: iteration i lands in bin i-1 (clamped to the last
@@ -737,14 +762,24 @@ def _maintain_core(
     counts.
     """
     old_dstore = state.dstore  # frozen pre-maintenance snapshot (functional)
+    num_local = state.cur.shape[-1]
     if axis is None:
         init_full = state.init
         live0 = dirty.any()
         horizon0 = stored_horizon(state.dstore)
+        dst = g.dst
     else:
         init_full = jax.lax.all_gather(state.init, axis, axis=1, tiled=True)
         live0 = jax.lax.psum(dirty.any().astype(jnp.int32), axis) > 0
         horizon0 = jax.lax.pmax(stored_horizon(state.dstore), axis)
+        off = jax.lax.axis_index(axis).astype(jnp.int32) * num_local
+        dst = _local_dst(g.dst, off, num_local)
+    # the edges in destination order, once per sweep: the body's in-edge
+    # reductions read each destination's run in place of a per-edge scatter
+    with jax.named_scope("cqp.edge_order"):
+        order = edge_order(
+            g.src, dst, g.valid, effective_weight(cfg, g), num_local
+        )
 
     body = partial(
         _sweep_body,
@@ -755,6 +790,7 @@ def _maintain_core(
         old_dstore,
         state.active,
         state.join_mat,
+        order,
         axis,
     )
 
@@ -779,7 +815,6 @@ def _maintain_core(
             & ((c.i == 1) | (c.i <= horizon + 1))
         )
 
-    num_local = state.cur.shape[-1]
     zeros = jnp.zeros((cfg.num_queries, num_local), bool)
     c0 = _Carry(
         i=jnp.int32(1),

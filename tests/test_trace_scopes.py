@@ -131,9 +131,11 @@ def test_disabled_tracer_opens_no_annotation(installed, monkeypatch):
 
 # ------------------------------------------------------------ named programs
 SCOPES = {
-    ("coo", False): {"edge_scatter", "expand", "frontier", "store"},
-    ("coo", True): {"edge_scatter", "expand", "frontier", "store", "drop"},
-    ("fused", True): {"edge_scatter", "fused_sweep", "frontier", "drop"},
+    ("coo", False): {"edge_scatter", "edge_order", "expand", "frontier", "store"},
+    ("coo", True): {"edge_scatter", "edge_order", "expand", "frontier", "store",
+                    "drop"},
+    ("fused", True): {"edge_scatter", "edge_order", "fused_sweep", "frontier",
+                      "drop"},
 }
 
 
