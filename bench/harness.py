@@ -90,6 +90,19 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def shards(config: dict, chips: int | None = None) -> int:
+    """The number of chips the configuration's vertices are sharded over:
+    its ``shards``, or 1 where it has none.  Raises where they do not
+    divide ``num_vertices``, or differ from the cell's ``chips``."""
+    n, v = int(config.get("shards", 1)), int(config["num_vertices"])
+    if n < 1 or v % n:
+        raise ValueError(f"shards {n} must be at least 1 and divide num_vertices {v}")
+    if chips is not None and chips != n:
+        raise ValueError(f"the cell asks for {chips} chips, and its configuration "
+                         f"shards its vertices over {n}: they must be equal")
+    return n
+
+
 def cell_spec(name: str, bench: dict | None = None, root: Path = ROOT) -> dict:
     """The cell ``name`` with its configuration, traffic and metrics."""
     bench = bench if bench is not None else load_benchmark(root)
@@ -97,11 +110,13 @@ def cell_spec(name: str, bench: dict | None = None, root: Path = ROOT) -> dict:
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; have {sorted(cells)}")
     cell = cells[name]
-    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    config = json.loads((root / entry["file"]).read_text())
+    shards(config, int(cell["chips"]))
     return {
         "name": name,
         "chips": int(cell["chips"]),
-        "config": json.loads((root / config["file"]).read_text()),
+        "config": config,
         "traffic": json.loads(
             (root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text()
         ),
@@ -180,13 +195,17 @@ def drop_config(config: dict, stream: EdgeStream):
 # ------------------------------------------------------------------- serving
 class Served:
     """The program under test: one session over the loaded graph, and what
-    each batch of updates did to it."""
+    each batch of updates did to it.  A configuration with ``shards`` runs
+    the session's vertex-sharded dense path over that many devices."""
 
     def __init__(self, config: dict, traffic: dict, stream: EdgeStream, drop) -> None:
         from repro.core.graph import DynamicGraph
         from repro.core.session import CQPSession
+        from repro.launch.mesh import make_data_mesh
 
         v = int(config["num_vertices"])
+        self.shards = shards(config)
+        self.mesh = make_data_mesh(self.shards) if "shards" in config else None
         ids = stream.loaded()
         edges = list(zip(stream.src[ids].tolist(), stream.dst[ids].tolist(),
                          stream.weight[ids].tolist()))
@@ -202,6 +221,7 @@ class Served:
             drop=drop,
             batch_capacity=self.batch,
             min_slots=int(config["num_queries"]),
+            mesh=self.mesh,
         )
         self.load_s = time.perf_counter() - t
         self.handles = []
@@ -235,13 +255,21 @@ class Served:
         gc.collect()
 
 
-def device_peak(stats: dict | None) -> int | None:
-    """The device's peak bytes: the allocator's peak of buffers in use,
-    plus the peak that loaded programs reserve for their temporaries and
-    code, which a TPU keeps apart at the bottom of its memory."""
-    if not stats:
-        return None
-    return stats["peak_bytes_in_use"] + stats["peak_bytes_reserved"]
+    def devices(self) -> list:
+        """The devices that hold the session's state."""
+        return list(self.mesh.devices.flat) if self.mesh is not None else jax.devices()[:1]
+
+
+def device_peak(stats: list) -> tuple[int | None, list]:
+    """The fullest device's peak bytes, and each device's, from their
+    ``memory_stats()``: the allocator's peak of buffers in use, plus the
+    peak that loaded programs reserve for their temporaries and code, which
+    a TPU keeps apart at the bottom of its memory.  ``(None, [])`` where a
+    device reports no statistics (the CPU)."""
+    if not stats or not all(stats):
+        return None, []
+    peaks = [s["peak_bytes_in_use"] + s["peak_bytes_reserved"] for s in stats]
+    return max(peaks), peaks
 
 
 def _stats(stats) -> dict:
@@ -341,15 +369,18 @@ class CellRun:
         reference.  Returns the record the metric readers read, with the
         comparison's numbers under ``checks``."""
         served = self.served
-        stats = jax.devices()[0].memory_stats()
+        stats = [d.memory_stats() for d in served.devices()]
         self.log(f"memory_stats: {stats}")
+        peak, peaks = device_peak(stats)
         rec = {
             "cell": self.cell["name"],
             "num_queries": len(self.sources),
+            "shards": served.shards,
             "setup": self.setup,
             "window": w,
             "diff_bytes": served.session.nbytes(),
-            "peak_bytes": device_peak(stats),
+            "peak_bytes": peak,
+            "peak_bytes_devices": peaks,
             "failed": sum(b["updates"] for b in w.batches if b["capped"]),
             "trace": None,
         }
@@ -357,7 +388,9 @@ class CellRun:
             events = trace_reduce.load(tracedir)
             self.log(f"trace: {trace_reduce.summary(events)}")
             rec["trace"] = trace_reduce.reduce(events)
-            self.log(f"trace modules: {rec['trace']['modules']}")
+            self.log(f"trace modules: {rec['trace']['modules']}; busy per device "
+                     f"{rec['trace']['busy_s_devices']} s, exposed collective "
+                     f"{rec['trace']['collective_s']} s")
         final = served.answers(served.latest)
         sampled = None
         if self.sample and self.sample["k"] != self.batches[-1]["k"]:

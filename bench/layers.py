@@ -55,8 +55,6 @@ SCOPE = re.compile(r"(?:^|/)cqp\.([a-z_]+)(?=/|$)")
 HLO_LINE = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$")
 # a computation's header
 HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) ")
-# ops that hold other ops: their time is their body's, counted there
-CONTAINERS = ("while", "call", "conditional")
 SPAN_PREFIXES = ("bench.", "cqp.")
 # device-time metrics: the scopes each one sums
 SCOPE_METRICS = {
@@ -158,7 +156,8 @@ def scopes(events, hlo: dict, module: str = CHUNK_PROGRAM) -> dict:
     for (p, ln, n, s, d) in events:
         # "%fusion.80 = f32[...] fusion(...)": the op's name alone
         name = n.split(" = ")[0].lstrip("%")
-        if p != dev or ln != trace_reduce.OPS_LINE or name.split(".")[0] in CONTAINERS:
+        if (p != dev or ln != trace_reduce.OPS_LINE
+                or name.split(".")[0] in trace_reduce.CONTAINERS):
             continue
         k = bisect.bisect_right(starts, s) - 1
         if k >= 0 and s < runs[k][1]:

@@ -1,5 +1,6 @@
-"""device_idle: share of the traced window in which no operation ran on the
-device, in percent."""
+"""device_idle: share of the traced window in which no operation ran on a
+device, in percent.  On several devices it reads the mean of their busy
+seconds (``busy_s``), not the busiest or the idlest."""
 
 
 def read(rec):

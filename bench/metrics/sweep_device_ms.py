@@ -1,7 +1,7 @@
 """sweep_device_ms: device time of one run of the chunk program, from the
-profile.  The chunk program is jitted from a ``functools.partial`` and shows
-as ``jit__unknown``, so the reader takes the program with the most device
-time in the window, which is the chunk program in every cell."""
+profile.  The reader takes the program with the most device time in the
+window, which is the chunk program (``jit_cqp_chunk_step``, or its
+``_sharded`` twin) in every cell."""
 
 
 def read(rec):

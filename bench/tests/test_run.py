@@ -76,6 +76,7 @@ def test_benchmark_json_keeps_the_contract():
     names = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
              for x in b[k]]
     assert all(NAME.match(n) for n in names) and len(set(names)) == len(names)
+    shards = {}
     for c in b["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"].startswith("bench/") and (harness.ROOT / c["file"]).is_file()
@@ -83,10 +84,13 @@ def test_benchmark_json_keeps_the_contract():
         assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
         cfg = json.loads((harness.ROOT / c["file"]).read_text())
         assert cfg["reduced"] == c["reduced"] and cfg["source"] == c["source"]
+        shards[c["name"]] = cfg.get("shards", 1)
     pairs = set()
     for w in b["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+        # a cell runs on as many chips as its configuration has shards
+        assert w["chips"] == shards[w["config"]]
         assert (w["config"], w["traffic"]) not in pairs
         pairs.add((w["config"], w["traffic"]))
     e2e = {m["name"]: m for m in b["end_to_end"]}

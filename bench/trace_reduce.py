@@ -7,7 +7,12 @@ so a test can hand it a synthetic trace.
 * The window is the harness's ``bench.window`` span on the host.
 * Busy time is the union of the intervals in which an operation ran on a
   device (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane), clipped
-  to the window and averaged over the devices.
+  to the window: each device's, and their mean.
+* Exposed collective time, on each device, is the time in the window in
+  which a collective op (``all-gather``, ``all-reduce``, ``reduce-scatter``,
+  ``collective-permute``, ``all-to-all``, with their ``-start``/``-done``
+  forms) ran and no other op did, other than the ``while``, ``call`` and
+  ``conditional`` ops that hold the others; then the mean over devices.
 * The idle time of the first device is split at every harness span
   boundary (``bench.*``, other than the window), and each piece goes to the
   innermost span that covers it, or to ``host`` where none does.
@@ -29,6 +34,10 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 WINDOW = "bench.window"
 SPAN_PREFIX = "bench."
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute",
+               "all-to-all")
+# ops that hold other ops: their time is their body's, counted there
+CONTAINERS = ("while", "call", "conditional")
 
 
 def load(directory: str) -> list[tuple[str, str, str, float, float]]:
@@ -104,6 +113,20 @@ def _idle_by_span(u: np.ndarray, w0: float, w1: float, spans) -> dict:
     return out
 
 
+def _length(intervals: list) -> float:
+    u = _union(np.asarray(intervals, np.float64).reshape(-1, 2))
+    return float((u[:, 1] - u[:, 0]).sum())
+
+
+def _exposed(ops: list) -> float:
+    """Time in which a collective of ``ops`` (``(start, end, name)``) ran
+    and no other leaf op did."""
+    coll = [(a, b) for a, b, n in ops if n.startswith(COLLECTIVES)]
+    other = [(a, b) for a, b, n in ops
+             if not n.startswith(COLLECTIVES) and n.split(".")[0] not in CONTAINERS]
+    return _length(coll + other) - _length(other) if coll else 0.0
+
+
 def reduce(events, top: int = 10) -> dict:
     windows = [(s, s + d) for (p, ln, n, s, d) in events
                if n == WINDOW and not DEVICE_PLANE.match(p)]
@@ -115,19 +138,20 @@ def reduce(events, top: int = 10) -> dict:
          if n.startswith(SPAN_PREFIX) and n != WINDOW and not DEVICE_PLANE.match(p)),
     )
     devices = sorted({p for (p, *_r) in events if DEVICE_PLANE.match(p)})
-    busy, gaps_by_span = [], collections.Counter()
+    busy, exposed, gaps_by_span = [], [], collections.Counter()
     op_s, module_s, module_n = collections.Counter(), collections.Counter(), collections.Counter()
     for k, dev in enumerate(devices):
-        ops = np.asarray([(max(s, w0), min(s + d, w1)) for (p, ln, n, s, d) in events
-                          if p == dev and ln == OPS_LINE and s < w1 and s + d > w0],
-                         np.float64).reshape(-1, 2)
-        u = _union(ops)
+        # "%fusion.80 = f32[...] fusion(...)": the op's name alone
+        ops = [(max(s, w0), min(s + d, w1), n.split(" = ")[0].lstrip("%"))
+               for (p, ln, n, s, d) in events
+               if p == dev and ln == OPS_LINE and s < w1 and s + d > w0]
+        u = _union(np.asarray([(a, b) for a, b, _n in ops], np.float64).reshape(-1, 2))
         busy.append(float((u[:, 1] - u[:, 0]).sum()) * 1e-9)
+        exposed.append(_exposed(ops) * 1e-9)
         for (p, ln, n, s, d) in events:
             if p != dev or s >= w1 or s + d <= w0:
                 continue
             if ln == OPS_LINE:
-                # "%fusion.80 = f32[...] fusion(...)": the op's name alone
                 op_s[n.split(" = ")[0].lstrip("%")] += d * 1e-9
             elif ln == MODULES_LINE:
                 name = n.split("(")[0]
@@ -139,6 +163,8 @@ def reduce(events, top: int = 10) -> dict:
         "window_s": (w1 - w0) * 1e-9,
         "devices": len(devices),
         "busy_s": float(np.mean(busy)) if busy else 0.0,
+        "busy_s_devices": busy,
+        "collective_s": float(np.mean(exposed)) if exposed else 0.0,
         "device_ops": [[n, s] for n, s in op_s.most_common(top)],
         "idle_gaps": [[n, s] for n, s in gaps_by_span.most_common(top)],
         "modules": {n: {"count": module_n[n], "seconds": module_s[n]} for n in module_s},
